@@ -26,7 +26,8 @@ hardware).  Four claims, written to ``BENCH_mesh_scaleout.json``:
 
 Usage:  PYTHONPATH=src python benchmarks/mesh_scaleout.py [--quick] [--out F]
 (Needs a fresh process — raises if a jax backend with <8 devices already
-initialized; ``benchmarks/run.py --only mesh`` handles the subprocess.)
+initialized; ``python -m benchmarks.run --only mesh`` runs it in-process
+before anything else touches jax.)
 """
 from __future__ import annotations
 
@@ -216,7 +217,8 @@ def main(argv=None):
     if jax.device_count() < 8:
         raise RuntimeError(
             f"mesh_scaleout needs 8 devices, found {jax.device_count()} — "
-            "run in a fresh process (benchmarks/run.py --only mesh does)")
+            "run it as its own invocation (python -m benchmarks.run --only "
+            "mesh, or this script)")
     reps = 10 if args.smoke else 40
     warmup = 3 if args.smoke else 8
 

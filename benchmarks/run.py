@@ -18,8 +18,9 @@
                  advance+score+select dispatch) vs N host generators
   mesh         — production-mesh scale-out: fused score on a real 8-device
                  emulated mesh vs the sequential legacy path, weak-scaling
-                 curves, and bit-identity parity flags (subprocess: the
-                 device count must be set before jax initializes)
+                 curves, and bit-identity parity flags (only as its own
+                 invocation, ``--only mesh``: the device count must be set
+                 before jax initializes, so a full run skips it)
   kernels      — Pallas-path microbenchmarks (XLA schedule, host timing)
 
 ``python -m benchmarks.run`` runs everything; ``--only <name>`` filters.
@@ -34,8 +35,6 @@ the machine that produced it is gone.
 from __future__ import annotations
 
 import argparse
-import os
-import subprocess
 import sys
 import time
 
@@ -119,15 +118,21 @@ def bench_fleet(smoke: bool):
 
 
 def bench_mesh(smoke: bool):
-    _section("Production-mesh scale-out (8 emulated devices, subprocess)")
-    # the emulated-device count locks on first jax backend init, and any
-    # section above may already have initialized it — so the mesh
-    # benchmark always runs in a fresh interpreter (same pattern as the
-    # roofline's 512-device tables)
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "mesh_scaleout.py")
-    subprocess.run([sys.executable, script]
-                   + (["--smoke"] if smoke else []), check=True)
+    _section("Production-mesh scale-out (8 emulated devices)")
+    # the emulated-device count locks when jax first initializes, and a
+    # child process would meet a parent that may already hold the device —
+    # so the mesh benchmark runs only as its own top-level invocation:
+    # in this process when nothing has touched jax yet (`--only mesh`),
+    # and otherwise not at all
+    from repro.launch import platform as _platform
+
+    if _platform.backend_initialized():
+        print("skipped: jax is already initialized in this process; run "
+              "`python -m benchmarks.run --only mesh` (or "
+              "benchmarks/mesh_scaleout.py) as its own invocation")
+        return
+    from benchmarks import mesh_scaleout   # requests 8 devices on import
+    mesh_scaleout.main(["--smoke"] if smoke else [])
 
 
 def bench_kernels():
@@ -186,6 +191,9 @@ def main():
                     help="few iterations (CI)")
     args = ap.parse_args()
 
+    from repro.launch.platform import enable_compile_cache
+
+    enable_compile_cache()     # imports jax, initializes no backend
     t0 = time.time()
     if args.only in (None, "speedup"):
         bench_speedup(args.simulate)

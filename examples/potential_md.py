@@ -36,6 +36,7 @@ sys.path.insert(0, "examples")
 
 from repro.configs.pal_potential import PALRunConfig, PotentialConfig
 from repro.core import PAL
+from repro.launch.platform import enable_compile_cache
 from repro.models import potential as pot
 from repro.training import CommitteeTrainer
 from quickstart import (LJOracle, MDGenerator, PCFG, make_committee_spec,
@@ -179,6 +180,7 @@ def main():
                     help="device-resident exploration-fleet size; 0 runs "
                          "the legacy host-generator path")
     args = ap.parse_args()
+    enable_compile_cache()
 
     coords_test, forces_test = make_test_set()
     print(f"label budget: {args.budget} oracle calls"

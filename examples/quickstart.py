@@ -31,6 +31,7 @@ sys.path.insert(0, "src")
 from repro.configs.pal_potential import PALRunConfig, PotentialConfig
 from repro.core import PAL, CommitteeSpec, UserGene, UserOracle
 from repro.core import committee as cmte
+from repro.launch.platform import enable_compile_cache
 from repro.models import potential as pot
 
 PCFG = PotentialConfig(n_atoms=6, committee_size=4, hidden=(64, 64), n_rbf=24)
@@ -112,6 +113,7 @@ def main(argv=None):
     ap.add_argument("--timeout", type=float, default=45.0,
                     help="run budget in seconds (CI smoke uses a short one)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     cfg = PALRunConfig(
         result_dir=tempfile.mkdtemp(prefix="pal_quickstart_"),
         gene_process=8, orcl_process=4, pred_process=4, ml_process=4,

@@ -535,9 +535,9 @@ class FusedEngine(UQEngine):
 
         return jax.tree.map(leaf, carry)
 
-    def _constrain_preds(self, preds, nb: int):
-        """Pin the (K, nb, d) prediction tensor's in-program layout: K
-        gathered (unsharded), rows kept on the batch sharding.
+    def _committee_uq(self, preds, nb: int):
+        """The ``committee_uq`` statistics of the (K, nb, d) prediction
+        tensor, with K gathered (unsharded) and rows kept on the mesh.
 
         The Welford committee-UQ reduction runs over K; leaving K sharded
         over 'model' makes XLA reduce local partials then all-reduce,
@@ -549,10 +549,17 @@ class FusedEngine(UQEngine):
         axis ('data' AND 'model', greedy divisibility like rules.pspec):
         on a committee-axis mesh the gathered tensor's UQ work is then
         row-split across the devices instead of redundantly replicated.
-        No-op without a mesh."""
+
+        On a mesh the statistics run per row shard inside a
+        ``shard_map``: rows are independent, so this is the same
+        arithmetic as one unsharded call, and the compiler cannot
+        partition a Pallas (Mosaic) kernel by itself."""
+        def uq(p):
+            return self._ops.committee_uq(p, self.threshold, impl=self.impl,
+                                          block_n=self.block_n)
+
         if self._mesh_rules is None:
-            return preds
-        from jax.sharding import NamedSharding
+            return uq(preds)
         from jax.sharding import PartitionSpec as P
 
         mesh = self._mesh_rules.mesh
@@ -562,9 +569,11 @@ class FusedEngine(UQEngine):
             if a in mesh.shape and nb % (prod * sz) == 0:
                 chosen.append(a)
                 prod *= sz
-        row_axes = tuple(chosen) if chosen else None
-        return jax.lax.with_sharding_constraint(
-            preds, NamedSharding(mesh, P(None, row_axes, None)))
+        rows = tuple(chosen) if chosen else None
+        return jax.shard_map(
+            uq, mesh=mesh, in_specs=P(None, rows, None),
+            out_specs=(P(rows, None),) + (P(rows),) * 4,
+            check_vma=False)(preds)
 
     def _jit_shardings(self, nb: int):
         """(in_shardings, out_shardings) for one bucket's compiled dispatch.
@@ -593,10 +602,8 @@ class FusedEngine(UQEngine):
             def fused(cparams, x, n_valid, stream, rstate):
                 # trace-time counter: fires once per (bucket) compilation
                 self.trace_counts[nb] = self.trace_counts.get(nb, 0) + 1
-                preds = self._constrain_preds(self.apply(cparams, x), nb)
-                mean, sstd, cstd, _, finite = self._ops.committee_uq(
-                    preds, self.threshold, impl=self.impl,
-                    block_n=self.block_n)
+                mean, sstd, cstd, _, finite = self._committee_uq(
+                    self.apply(cparams, x), nb)
                 valid = jnp.arange(nb) < n_valid
                 stats = UQStats(x=x, mean=mean, scalar_std=sstd,
                                 component_std=cstd, valid=valid,
@@ -712,10 +719,8 @@ class FusedEngine(UQEngine):
                 self.step_trace_counts[key] = \
                     self.step_trace_counts.get(key, 0) + 1
                 x, mid = step_fn(carry)
-                preds = self._constrain_preds(self.apply(cparams, x), nb)
-                mean, sstd, cstd, _, finite = self._ops.committee_uq(
-                    preds, self.threshold, impl=self.impl,
-                    block_n=self.block_n)
+                mean, sstd, cstd, _, finite = self._committee_uq(
+                    self.apply(cparams, x), nb)
                 valid = jnp.arange(nb) < n_valid
                 stats = UQStats(x=x, mean=mean, scalar_std=sstd,
                                 component_std=cstd, valid=valid,

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import threading
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -176,6 +177,11 @@ class WalkerFleet:
         self._cache_key = f"fleet{next(_FLEET_IDS)}"
         self.steps_done = 0
         self.last: Optional[FusedStepOut] = None
+        # carry lock: ``score_after`` DONATES the carry off-CPU, so between
+        # the dispatch and the rebinding of ``_carry`` the old buffers are
+        # deleted.  Every reader (a checkpoint from the manager thread,
+        # ``report``) and writer of ``_carry`` holds this lock.
+        self._lock = threading.Lock()
 
         pad = np.zeros((self.nb, self.dim), np.float32)
         pad[:self.n_walkers] = x0
@@ -258,10 +264,10 @@ class WalkerFleet:
                     self.poison_walker(int(ev.arg))
                 else:
                     self.chaos.execute(ev)
-        carry, out = self.engine.score_after(
-            self._step_fn, self._carry, self.n_walkers, self.nb,
-            react_fn=self._react_fn, cache_key=self._cache_key)
-        self._carry = carry
+        with self._lock:
+            self._carry, out = self.engine.score_after(
+                self._step_fn, self._carry, self.n_walkers, self.nb,
+                react_fn=self._react_fn, cache_key=self._cache_key)
         self.steps_done += 1
         self.last = out
         return out
@@ -270,20 +276,21 @@ class WalkerFleet:
     def positions(self) -> np.ndarray:
         """(n_walkers, d) host snapshot of walker positions — diagnostics
         and tests only; the hot loop never calls this."""
-        return np.asarray(self._carry["x"][:self.n_walkers])
+        with self._lock:
+            return np.asarray(self._carry["x"][:self.n_walkers])
 
     def stats(self) -> Dict[str, Any]:
         """Host snapshot of fleet health (PAL.report) — one transfer per
         call, off the hot path."""
-        c = self._carry
+        with self._lock:
+            c = {k: np.asarray(self._carry[k])
+                 for k in ("step", "restarts", "nan_resets", "counts")}
         return {
             "walkers": self.n_walkers,
             "steps": int(c["step"]),
-            "restarts": int(np.sum(
-                np.asarray(c["restarts"][:self.n_walkers]))),
+            "restarts": int(np.sum(c["restarts"][:self.n_walkers])),
             "nan_resets": int(c["nan_resets"]),
-            "uncertain_streak_max": int(np.max(
-                np.asarray(c["counts"][:self.n_walkers]))),
+            "uncertain_streak_max": int(np.max(c["counts"][:self.n_walkers])),
         }
 
     # ------------------------------------------------------------ checkpoint
@@ -291,20 +298,24 @@ class WalkerFleet:
         """Full host-numpy snapshot of the carry — including the per-walker
         RNG keys and step counter, so a restored fleet replays the exact
         trajectory (bit-identical resume)."""
-        return {k: np.asarray(v) for k, v in self._carry.items()}
+        with self._lock:
+            return {k: np.asarray(v) for k, v in self._carry.items()}
 
     def load_state_dict(self, state: Dict[str, np.ndarray]):
         if set(state) != set(self._carry):
             raise ValueError(
                 f"fleet snapshot keys {sorted(state)} do not match the "
                 f"carry {sorted(self._carry)}")
-        self._carry = self.engine.place_carry(
+        carry = self.engine.place_carry(
             {k: jnp.asarray(v) for k, v in state.items()}, self.nb)
+        with self._lock:
+            self._carry = carry
 
     # ----------------------------------------------------------------- chaos
     def poison_walker(self, i: int):
         """Set walker i's position non-finite (chaos ``nan_walker``): the
         next fused step routes it through the restart gate — reset to its
         trusted state, never a crash."""
-        self._carry = dict(
-            self._carry, x=self._carry["x"].at[i].set(jnp.nan))
+        with self._lock:
+            self._carry = dict(
+                self._carry, x=self._carry["x"].at[i].set(jnp.nan))

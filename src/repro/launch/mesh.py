@@ -18,12 +18,22 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh on the real host device (smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes, like ``jax.sharding.Mesh``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which refuse layouts
+    this repo leaves to the compiler (the replay ring's sharded
+    ``dynamic_update_slice`` among them)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(shape)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_scaleout_mesh(data: int = 0, model: int = 1):

@@ -15,6 +15,9 @@ Three kinds of knob live here, in order of how early they must fire:
   * **jax.config toggles** (``set_platform``, ``enable_x64``,
     ``set_debug_nan``) — applied through ``jax.config.update``; safe at
     any time before the relevant behavior is traced.
+  * **the persistent compile cache** (``enable_compile_cache``) — where
+    compiled programs are kept between processes; entry points call it
+    before their first compile.
   * **introspection** (``describe``) — the resolved platform / device kind
     / device count / mesh-relevant process info, recorded by every
     benchmark writer so a ``BENCH_*.json`` is interpretable across
@@ -54,15 +57,11 @@ GPU_AUTOTUNE_FLAGS = (
 def backend_initialized() -> bool:
     """Whether a jax backend has already been created in this process —
     the point after which XLA_FLAGS edits are dead letters."""
-    jx = sys.modules.get("jax")
-    if jx is None:
+    if "jax" not in sys.modules:
         return False
-    try:
-        from jax._src import xla_bridge  # noqa: PLC0415
+    from jax._src import xla_bridge  # noqa: PLC0415
 
-        return bool(xla_bridge._backends)  # noqa: SLF001
-    except Exception:  # noqa: BLE001  — private API moved: assume locked
-        return True
+    return xla_bridge.backends_are_initialized()
 
 
 def requested_host_devices() -> Optional[int]:
@@ -119,9 +118,9 @@ def apply_gpu_autotune() -> None:
 
 
 def set_platform(platform: str) -> None:
-    """Pin the jax platform ('cpu' | 'gpu' | 'tpu').  Uses jax.config when
-    jax is already importable, the JAX_PLATFORMS env var otherwise (both
-    are honored at backend init)."""
+    """Pin the jax platform ('cpu' | 'gpu' | 'tpu').  Uses the
+    ``jax_platforms`` config when jax is already imported, the
+    JAX_PLATFORMS env var otherwise (both are honored at backend init)."""
     platform = str(platform).lower()
     if platform not in ("cpu", "gpu", "tpu"):
         raise ValueError(f"set_platform: unknown platform {platform!r}")
@@ -129,7 +128,7 @@ def set_platform(platform: str) -> None:
         raise RuntimeError(
             f"set_platform({platform!r}): jax backend already initialized")
     if "jax" in sys.modules:
-        sys.modules["jax"].config.update("jax_platform_name", platform)
+        sys.modules["jax"].config.update("jax_platforms", platform)
     else:
         os.environ["JAX_PLATFORMS"] = platform
 
@@ -149,6 +148,29 @@ def set_debug_nan(flag: bool = True) -> None:
         sys.modules["jax"].config.update("jax_debug_nans", bool(flag))
     else:
         os.environ["JAX_DEBUG_NANS"] = "1" if flag else "0"
+
+
+# the checkout's own compile-cache directory (listed in .gitignore).  It is
+# a fixed path so that every later process run from this checkout finds
+# what earlier ones wrote: a temp, pid or time-based path would never hit
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile.  When ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads
+    its directory from there and nothing else is set; otherwise the cache
+    goes to the fixed in-checkout ``REPO_CACHE_DIR``.  Returns the
+    directory in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax  # noqa: PLC0415
+
+    jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+    return REPO_CACHE_DIR
 
 
 @dataclasses.dataclass(frozen=True)

@@ -13,12 +13,14 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.platform import enable_compile_cache
 from repro.launch.train import reduced_config
 from repro.models import model_zoo
 from repro.serving import ServeEngine
 
 
 def main():
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="llama3.2-1b")
     p.add_argument("--preset", default="smoke", choices=["smoke", "100m",

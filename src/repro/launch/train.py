@@ -24,6 +24,7 @@ from repro.configs import get_arch
 from repro.configs.base import ShapeConfig, TrainConfig
 from repro.data.prefetch import Prefetcher
 from repro.data.synthetic import SyntheticTokenStream
+from repro.launch.platform import enable_compile_cache
 from repro.models import model_zoo
 from repro.training import TrainState, make_train_state, make_train_step
 
@@ -59,6 +60,7 @@ def reduced_config(cfg, preset: str):
 
 
 def main():
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="llama3.2-1b")
     p.add_argument("--preset", default="smoke",

@@ -14,10 +14,16 @@ mesh, including stateful-rule state, checkpoint round-trips of sharded
 state, and the device-resident fleet carry.
 
 Known exception (asserted, with tolerance): on the (1 x 8) COMMITTEE-axis
-mesh the trainer's params drift at the ~1 ULP level — XLA fuses the
-grad+Adam chain differently under SPMD partitioning (FMA/accumulation
-order), which no sharding constraint can pin.  Scoring on that mesh is
-still bit-identical.
+mesh every device runs its own members' forward, and XLA:CPU rounds those
+per-device matmuls differently from the one batched K-member matmul of the
+unsharded program (forward outputs differ by up to ~4e-7 absolute).  So
+the trainer's params drift at the ~1 ULP level per step (the grad+Adam
+chain also fuses differently under SPMD partitioning), and scoring is
+bounded rather than bit-identical: std outputs within a few ULP, the mean
+within the same absolute bound as the trainer (its relative error is only
+large where the mean itself is near zero), the rule state within that
+bound too, and the selection mask equal.  No sharding constraint can pin the member matmuls
+without gathering the committee, which would undo the committee axis.
 """
 from __future__ import annotations
 
@@ -74,6 +80,17 @@ def _uq_equal(a, b):
                for f in ("mean", "scalar_std", "component_std", "mask"))
 
 
+def _assert_uq_ulp_bounded(a, b):
+    """Committee-axis scoring bound (module docstring): the selection is
+    identical, the statistics agree to the trainer's committee-axis
+    tolerance."""
+    np.testing.assert_array_equal(np.asarray(b.mask), np.asarray(a.mask))
+    for f in ("mean", "scalar_std", "component_std"):
+        np.testing.assert_allclose(np.asarray(getattr(b, f)),
+                                   np.asarray(getattr(a, f)),
+                                   rtol=1e-5, atol=1e-6)
+
+
 def _tree_equal(a, b):
     la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
     return len(la) == len(lb) and all(
@@ -83,15 +100,28 @@ def _tree_equal(a, b):
 @pytest.mark.parametrize("shape", [(8, 1), (1, 8)], ids=["data8", "model8"])
 def test_score_bitidentical_with_stateful_rules(cparams, shape):
     """4 advancing rounds: outputs AND BudgetRule/RollingReweightRule
-    state stay bit-identical to the unsharded engine on both mesh
-    orientations."""
+    state stay bit-identical to the unsharded engine on the data-axis
+    mesh; on the committee-axis mesh the statistics and the controller
+    state are ULP-bounded (see the module docstring) while the selection
+    mask stays identical."""
     e0 = _engine(cparams, None, with_rules=True)
     e8 = _engine(cparams, make_scaleout_mesh(*shape), with_rules=True)
+    committee_axis = shape[1] > 1
     rng = np.random.RandomState(1)
     for _ in range(4):
         xs = rng.randn(61, D).astype(np.float32)
-        assert _uq_equal(e0.score(list(xs)), e8.score(list(xs)))
-    assert _tree_equal(e0.state_dict(), e8.state_dict())
+        a, b = e0.score(list(xs)), e8.score(list(xs))
+        if committee_axis:
+            _assert_uq_ulp_bounded(a, b)
+        else:
+            assert _uq_equal(a, b)
+    s0, s8 = e0.state_dict(), e8.state_dict()
+    if committee_axis:
+        for x, y in zip(jax.tree.leaves(s0), jax.tree.leaves(s8)):
+            np.testing.assert_allclose(np.asarray(y), np.asarray(x),
+                                       rtol=1e-5, atol=1e-6)
+    else:
+        assert _tree_equal(s0, s8)
 
 
 def test_score_ndarray_fastpath_matches_list(cparams):

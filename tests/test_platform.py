@@ -166,3 +166,52 @@ def test_eight_device_mesh_end_to_end_subprocess():
     """)
     assert r.returncode == 0, r.stderr
     assert "MESH8_OK" in r.stdout
+
+
+def test_set_platform_pins_jax_platforms_after_import():
+    r = _run("""
+        import jax
+        from repro.launch import platform as plat
+        plat.set_platform("cpu")
+        assert jax.config.jax_platforms == "cpu", jax.config.jax_platforms
+        print("PINNED")
+    """, JAX_PLATFORMS="")
+    assert r.returncode == 0, r.stderr
+    assert "PINNED" in r.stdout
+
+
+def test_compile_cache_uses_env_dir_and_sets_no_other(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins: enable_compile_cache leaves jax's
+    own reading of it alone, and compiled programs land there."""
+    r = _run("""
+        import os
+        import jax, jax.numpy as jnp
+        from repro.launch import platform as plat
+        want = os.environ["JAX_COMPILATION_CACHE_DIR"]
+        assert plat.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        jax.jit(lambda x: jnp.sin(x) * 2.0)(jnp.ones(4)).block_until_ready()
+        assert os.listdir(want), "no cache entry written"
+        print("ENV_CACHE")
+    """, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    assert r.returncode == 0, r.stderr
+    assert "ENV_CACHE" in r.stdout
+
+
+def test_compile_cache_defaults_to_fixed_ignored_repo_dir():
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    r = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent("""
+            import jax
+            from repro.launch import platform as plat
+            path = plat.enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == path
+            print(path)
+        """)], capture_output=True, text=True, cwd=REPO, timeout=300,
+        env=dict(env, PYTHONPATH=os.path.join(REPO, "src")))
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == os.path.join(REPO, ".jax_cache")
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

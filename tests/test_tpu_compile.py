@@ -1,0 +1,117 @@
+"""Compile the main path's Pallas kernel for a TPU v5e that is described,
+not attached.
+
+The TPU compiler ships with jaxlib's TPU support and compiles for a chip
+described by ``jax.experimental.topologies``: what it refuses here (block
+shapes off the (8, 128) tiling, 1-D vector layouts, unpartitionable
+kernels) it would refuse on the chip.  Nothing runs, so these tests say
+nothing about results or times; interpret-mode parity lives in
+tests/test_committee_uq.py.
+
+The topology is described inside a module fixture, never at import, and
+every compile happens in this process: only one process may load the TPU
+library at a time, and it keeps it until it exits.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.pal_potential import PotentialConfig
+from repro.core import acquisition as acq
+from repro.kernels import committee_uq as cuq
+from repro.models import potential as pot
+from repro.sharding.rules import MeshRules, committee_shardings
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("K,n,d", [
+    (4, 8, 24),        # PotentialConfig(): 8 atoms x 3 force components,
+    (4, 256, 24),      # at the smallest engine bucket, a fleet bucket,
+    (4, 1024, 24),     # and a multi-block bucket
+    (8, 256, 1),       # energies: one output component
+])
+def test_committee_uq_compiles_for_v5e(one_chip, K, n, d):
+    preds = jax.ShapeDtypeStruct((K, n, d), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda p: cuq.committee_uq(p, 0.05)).lower(
+        preds).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _member_forces(p, flat_batch):
+    cfg = PotentialConfig()
+
+    def one(flat):
+        _, f = pot.energy_forces(p, flat.reshape(cfg.n_atoms, 3), cfg)
+        return f.reshape(-1)
+    return jax.vmap(one)(flat_batch)
+
+
+@pytest.mark.parametrize("shape", [None, (4, 1), (1, 4)],
+                         ids=["one_chip", "data4", "committee4"])
+def test_fused_pallas_score_compiles_on_v5e_meshes(topo, shape):
+    """The whole fused score dispatch (committee forward + Pallas UQ +
+    rules) at PotentialConfig() widths, on one chip and on the (4, 1)
+    data and (1, 4) committee meshes of a 2x2 host: the kernel must sit
+    inside a shard_map there, because the compiler cannot partition it."""
+    cfg = PotentialConfig()
+    nb, d = 256, 3 * cfg.n_atoms
+    cp_shape = jax.eval_shape(
+        lambda: pot.init_committee(cfg, jax.random.PRNGKey(0)))
+    cparams = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cp_shape)
+    eng = acq.FusedEngine(_member_forces, cparams, 0.05, impl="pallas")
+    if shape is None:
+        one = SingleDeviceSharding(topo.devices[0])
+        cp_sh = jax.tree.map(lambda _: one, cp_shape)
+        x_sh = scalar_sh = one
+    else:
+        # the engine places its parameters itself; a described chip holds
+        # no arrays, so hand it the mesh and pass shapes instead
+        mesh = Mesh(np.array(topo.devices[:4]).reshape(shape),
+                    ("data", "model"))
+        eng.mesh = mesh
+        eng._mesh_rules = MeshRules(mesh, None)
+        cp_sh = committee_shardings(eng._mesh_rules, cp_shape)
+        x_sh = eng._batch_sharding(nb)
+        scalar_sh = NamedSharding(mesh, P())
+    args = (
+        jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), cp_shape, cp_sh),
+        jax.ShapeDtypeStruct((nb, d), jnp.float32, sharding=x_sh),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar_sh),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar_sh),
+        (),
+    )
+    compiled = eng._compiled_locked(nb).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
