@@ -54,6 +54,7 @@ from repro.core.committee import (
     committee_size, make_committee_apply, member, shape_bucket, stack_members,
     update,
 )
+from repro.core.monitor import Monitor
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +399,8 @@ class FusedEngine(UQEngine):
                  *, rules: Optional[Sequence[SelectionRule]] = None,
                  impl: str = "xla", min_bucket: int = 8,
                  donate: bool = True, block_n: int = 128,
-                 mesh=None, sharding_rules=None):
+                 mesh=None, sharding_rules=None,
+                 monitor: Optional[Monitor] = None):
         from repro.kernels import ops as _ops
 
         self._ops = _ops
@@ -459,6 +461,9 @@ class FusedEngine(UQEngine):
         # quarantined at all
         self.last_finite_min: Optional[int] = None
         self.quarantine_rounds = 0
+        # host spans of score_after (engine.dispatch / wait /
+        # fetch_selected): the runtime passes its own Monitor
+        self.monitor = monitor if monitor is not None else Monitor()
 
     @property
     def size(self) -> int:
@@ -595,35 +600,51 @@ class FusedEngine(UQEngine):
         return in_sh, out_sh
 
     # ------------------------------------------------------------- compile
+    def _score_rows(self, cparams, x, nb: int, n_valid, stream, rstate):
+        """The traced body both programs share: committee forward, the
+        ``committee_uq`` statistics and the rule pipeline over one padded
+        (nb, in_dim) batch.  Returns the raw statistics ``(mean,
+        scalar_std, component_std, finite_members)``, the ``UQStats`` as
+        the rules left them (a re-weighting rule adjusts them for the
+        rules after it), the selection mask and the rules' new state."""
+        with jax.named_scope("committee_forward"):
+            preds = self.apply(cparams, x)
+        with jax.named_scope("committee_uq"):
+            mean, sstd, cstd, _, finite = self._committee_uq(preds, nb)
+        with jax.named_scope("selection"):
+            valid = jnp.arange(nb) < n_valid
+            stats = UQStats(x=x, mean=mean, scalar_std=sstd,
+                            component_std=cstd, valid=valid,
+                            n_valid=n_valid, stream=stream,
+                            finite_members=finite)
+            mask = valid
+            new_state, si = [], 0
+            for rule in self.rules:
+                if rule.stateful:
+                    stats, mask, ns = rule.apply_stateful(
+                        stats, mask, rstate[si])
+                    mask = jnp.asarray(mask) & valid
+                    new_state.append(ns)
+                    si += 1
+                else:
+                    mask = jnp.asarray(rule.apply(stats, mask)) & valid
+            # quarantine floor: a row no finite member scored carries no
+            # information — never selectable, whatever the rules say
+            mask = mask & (finite > 0)
+        return (mean, sstd, cstd, finite), stats, mask, tuple(new_state)
+
     def _compiled_locked(self, nb: int) -> Callable:
         # caller holds self._compile_lock
         fn = self._cache.get(nb)
         if fn is None:
-            def fused(cparams, x, n_valid, stream, rstate):
+            # the function's name names the program in a device trace:
+            # jit_engine_score
+            def engine_score(cparams, x, n_valid, stream, rstate):
                 # trace-time counter: fires once per (bucket) compilation
                 self.trace_counts[nb] = self.trace_counts.get(nb, 0) + 1
-                mean, sstd, cstd, _, finite = self._committee_uq(
-                    self.apply(cparams, x), nb)
-                valid = jnp.arange(nb) < n_valid
-                stats = UQStats(x=x, mean=mean, scalar_std=sstd,
-                                component_std=cstd, valid=valid,
-                                n_valid=n_valid, stream=stream,
-                                finite_members=finite)
-                mask = valid
-                new_state, si = [], 0
-                for rule in self.rules:
-                    if rule.stateful:
-                        stats, mask, ns = rule.apply_stateful(
-                            stats, mask, rstate[si])
-                        mask = jnp.asarray(mask) & valid
-                        new_state.append(ns)
-                        si += 1
-                    else:
-                        mask = jnp.asarray(rule.apply(stats, mask)) & valid
-                # quarantine floor: a row no finite member scored carries
-                # no information — never selectable, whatever the rules say
-                mask = mask & (finite > 0)
-                return mean, sstd, cstd, mask, finite, tuple(new_state)
+                (mean, sstd, cstd, finite), _, mask, new_state = \
+                    self._score_rows(cparams, x, nb, n_valid, stream, rstate)
+                return mean, sstd, cstd, mask, finite, new_state
             # donation is a no-op (plus a warning) on CPU — only request it
             # where XLA can actually alias the buffer
             donate = self.donate and jax.default_backend() != "cpu"
@@ -631,7 +652,7 @@ class FusedEngine(UQEngine):
             if self._mesh_rules is not None:
                 kw["in_shardings"], kw["out_shardings"] = \
                     self._jit_shardings(nb)
-            fn = jax.jit(fused, **kw)
+            fn = jax.jit(engine_score, **kw)
             self._cache[nb] = fn
         return fn
 
@@ -715,42 +736,29 @@ class FusedEngine(UQEngine):
         key = (ckey, nb)
         fn = self._step_cache.get(key)
         if fn is None:
-            def fused(cparams, carry, n_valid, stream, rstate):
+            # traces as jit_engine_step_score
+            def engine_step_score(cparams, carry, n_valid, stream, rstate):
                 self.step_trace_counts[key] = \
                     self.step_trace_counts.get(key, 0) + 1
-                x, mid = step_fn(carry)
-                mean, sstd, cstd, _, finite = self._committee_uq(
-                    self.apply(cparams, x), nb)
-                valid = jnp.arange(nb) < n_valid
-                stats = UQStats(x=x, mean=mean, scalar_std=sstd,
-                                component_std=cstd, valid=valid,
-                                n_valid=n_valid, stream=stream,
-                                finite_members=finite)
-                mask = valid
-                new_state, si = [], 0
-                for rule in self.rules:
-                    if rule.stateful:
-                        stats, mask, ns = rule.apply_stateful(
-                            stats, mask, rstate[si])
-                        mask = jnp.asarray(mask) & valid
-                        new_state.append(ns)
-                        si += 1
-                    else:
-                        mask = jnp.asarray(rule.apply(stats, mask)) & valid
-                mask = mask & (finite > 0)
-                new_carry = react_fn(mid, stats, mask) \
-                    if react_fn is not None else mid
+                with jax.named_scope("advance"):
+                    x, mid = step_fn(carry)
+                (mean, sstd, cstd, finite), stats, mask, new_state = \
+                    self._score_rows(cparams, x, nb, n_valid, stream, rstate)
+                with jax.named_scope("react"):
+                    new_carry = react_fn(mid, stats, mask) \
+                        if react_fn is not None else mid
                 # pack selected rows to the front (stable order) so the
                 # host can slice exactly n_selected rows off the device —
                 # unselected walkers never cross the boundary
-                order = jnp.argsort(~mask)
-                sel_x = jnp.take(x, order, axis=0)
-                n_sel = jnp.sum(mask).astype(jnp.int32)
+                with jax.named_scope("pack_selected"):
+                    order = jnp.argsort(~mask)
+                    sel_x = jnp.take(x, order, axis=0)
+                    n_sel = jnp.sum(mask).astype(jnp.int32)
                 return (new_carry, mean, sstd, cstd, mask, finite,
-                        n_sel, sel_x, tuple(new_state))
+                        n_sel, sel_x, new_state)
             donate = self.donate and jax.default_backend() != "cpu"
             kw: Dict[str, Any] = {"donate_argnums": (1,)} if donate else {}
-            fn = jax.jit(fused, **kw)
+            fn = jax.jit(engine_step_score, **kw)
             self._step_cache[key] = fn
         return fn
 
@@ -779,7 +787,8 @@ class FusedEngine(UQEngine):
         so a budget controller meters fleet and host traffic jointly.
         """
         key = (cache_key, nb)
-        with self._state_guard(advance):
+        mon = self.monitor
+        with mon.span("engine.dispatch"), self._state_guard(advance):
             args = (self.cparams, carry, np.int32(n), np.int32(stream),
                     self.rule_state)
             if key in self._step_warmed:
@@ -792,11 +801,14 @@ class FusedEngine(UQEngine):
             if advance:
                 self.rule_state = out[8]
         new_carry, mean, sstd, cstd, mask, finite, n_sel_d, sel_x = out[:8]
-        n_sel = int(n_sel_d)                       # one int32 to host
-        if n_sel:
-            selected = np.asarray(sel_x[:n_sel])   # selected rows only
-        else:
-            selected = np.zeros((0,) + tuple(sel_x.shape[1:]), np.float32)
+        with mon.span("engine.wait"):
+            n_sel = int(n_sel_d)                   # one int32 to host
+        with mon.span("engine.fetch_selected"):
+            if n_sel:                              # selected rows only
+                selected = np.asarray(sel_x[:n_sel])
+            else:
+                selected = np.zeros((0,) + tuple(sel_x.shape[1:]),
+                                    np.float32)
         with self._counter_lock:
             self.bytes_to_host += 4 + selected.nbytes
         return new_carry, FusedStepOut(
@@ -1024,6 +1036,7 @@ def make_engine(
     force_legacy: bool = False,
     mesh=None,
     sharding_rules=None,
+    monitor: Optional[Monitor] = None,
 ) -> UQEngine:
     """Build the acquisition engine from ``PALRunConfig`` knobs.
 
@@ -1037,6 +1050,8 @@ def make_engine(
 
     ``force_legacy`` overrides everything (used when a
     ``predict_all_override`` puts the user in control of raw predictions).
+
+    ``monitor`` receives the fused engine's spans (the runtime's own).
 
     ``mesh`` / ``sharding_rules`` select the mesh-parallel fused dispatch
     (committee over the ``model`` axis, request batch over ``data``); when
@@ -1077,4 +1092,5 @@ def make_engine(
         min_bucket=getattr(run_cfg, "uq_bucket", 8),
         mesh=mesh,
         sharding_rules=sharding_rules,
+        monitor=monitor,
     )

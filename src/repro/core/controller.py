@@ -106,7 +106,7 @@ class PredictionPool:
     def predict_uq(self, list_data_to_pred: List[np.ndarray]) -> acq.UQResult:
         """The one scoring call: engine -> UQResult (mean, scalar_std,
         component_std, mask)."""
-        with self.monitor.timer("exchange.predict"):
+        with self.monitor.span("exchange.predict"):
             return self.engine.score(list_data_to_pred)
 
     def predict_all(self, list_data_to_pred: List[np.ndarray]) -> np.ndarray:
@@ -250,24 +250,29 @@ class Exchange:
         The only per-iteration host traffic is the selected oracle
         candidates (plus one int32 count); patience/restart run as device
         rules, so the host ``PatienceTracker`` stays untouched."""
+        mon = self.monitor
         t0 = time.perf_counter()
-        if self.iteration % max(1, self.cfg.weight_pull_every) == 0:
-            self.prediction.refresh_weights()
-        with self.monitor.timer("exchange.predict"):
-            out = self.fleet.step()
-        self.monitor.incr("exchange.proposals", self.fleet.n_walkers)
-        if out.n_selected:
-            self.oracle_buffer.put(list(out.selected))
-            self.monitor.incr("exchange.queued_to_oracle", out.n_selected)
-        self.monitor.incr("exchange.iterations")
-        self.iteration += 1
-        max_steps = self.fleet.cfg.max_steps
-        if max_steps and self.fleet.steps_done >= max_steps:
-            return StopToken("fleet", "fleet max_steps reached")
-        if self.cfg.min_interval:
-            left = self.cfg.min_interval - (time.perf_counter() - t0)
-            if left > 0:
-                time.sleep(left)
+        with mon.span("exchange.round", step=self.iteration):
+            if self.iteration % max(1, self.cfg.weight_pull_every) == 0:
+                with mon.span("exchange.refresh_weights"):
+                    self.prediction.refresh_weights()
+            with mon.span("exchange.predict"):
+                out = self.fleet.step()
+            mon.incr("exchange.proposals", self.fleet.n_walkers)
+            if out.n_selected:
+                with mon.span("exchange.oracle_put"):
+                    self.oracle_buffer.put(list(out.selected))
+                mon.incr("exchange.queued_to_oracle", out.n_selected)
+            mon.incr("exchange.iterations")
+            self.iteration += 1
+            max_steps = self.fleet.cfg.max_steps
+            if max_steps and self.fleet.steps_done >= max_steps:
+                return StopToken("fleet", "fleet max_steps reached")
+            if self.cfg.min_interval:
+                left = self.cfg.min_interval - (time.perf_counter() - t0)
+                if left > 0:
+                    with mon.span("exchange.min_interval"):
+                        time.sleep(left)
         return None
 
 

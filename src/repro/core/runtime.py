@@ -161,7 +161,7 @@ class PAL:
             run_cfg, committee=committee, rules=rules,
             predict_all=self.prediction_pool.predict_all,
             force_legacy=predict_all_override is not None,
-            mesh=mesh, sharding_rules=sharding_rules)
+            mesh=mesh, sharding_rules=sharding_rules, monitor=self.monitor)
         self.prediction_pool.engine = self.engine
 
         # --- fused committee trainer (training/committee_trainer.py) -------
@@ -249,7 +249,7 @@ class PAL:
             # cross-round budget controller / re-weighting state, or every
             # retrain completion would charge a phantom exchange round
             # against the oracle budget
-            with self.monitor.timer("manager.fresh_score"):
+            with self.monitor.span("manager.fresh_score"):
                 return self.engine.score([np.asarray(x) for x in items],
                                          advance=False)
 
@@ -417,7 +417,7 @@ class PAL:
         attempt = 0
         while True:
             try:
-                with self.monitor.timer("oracle.run_calc"):
+                with self.monitor.span("oracle.run_calc"):
                     if self.chaos is not None:
                         self.chaos.check("oracle.task", rank=rank)
                     inp, label = oracle.run_calc(np.asarray(payload))
@@ -519,7 +519,7 @@ class PAL:
                     continue
             if self.chaos is not None:
                 self.chaos.check("trainer.loop")
-            with self.monitor.timer("train.retrain"):
+            with self.monitor.span("train.retrain"):
                 stop_run = trainer.retrain(self._trainer_pending[idx])
             # publish BEFORE noting completion: the completion wakes the
             # manager, whose dynamic_oracle_list re-score must see the
@@ -551,7 +551,7 @@ class PAL:
                 ev = self.chaos.take("trainer.nan_member")
                 if ev is not None:
                     trainer.poison_member(int(ev.arg))
-            with self.monitor.timer("train.retrain"):
+            with self.monitor.span("train.retrain"):
                 trainer.train(interrupt=self._trainer_pending[0])
             # publish BEFORE noting completion (see _trainer_loop): the
             # woken manager's re-score must run on the refreshed weights

@@ -189,6 +189,7 @@ def committee_uq(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="committee_uq",
     )(preds)
     stats = stats[:n]
     return (mean[:n], stats[:, _SSTD], stats[:, _CSTD],

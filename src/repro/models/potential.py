@@ -72,13 +72,16 @@ def init_committee(cfg: PotentialConfig, rng) -> Params:
 
 def energy(params: Params, coords: jnp.ndarray, cfg: PotentialConfig):
     """(A, 3) -> scalar energy."""
-    h = descriptors(coords, cfg)
-    n = len([k for k in params if k.startswith("w")])
-    for i in range(n):
-        h = h @ params[f"w{i}"] + params[f"b{i}"]
-        if i < n - 1:
-            h = jnp.tanh(h)
-    return jnp.sum(h)
+    # the named scopes label the operations in a device profile
+    with jax.named_scope("descriptor"):
+        h = descriptors(coords, cfg)
+    with jax.named_scope("mlp"):
+        n = len([k for k in params if k.startswith("w")])
+        for i in range(n):
+            h = h @ params[f"w{i}"] + params[f"b{i}"]
+            if i < n - 1:
+                h = jnp.tanh(h)
+        return jnp.sum(h)
 
 
 def energy_forces(params: Params, coords: jnp.ndarray, cfg: PotentialConfig):

@@ -57,6 +57,7 @@ import numpy as np
 
 from repro.configs.base import TrainConfig
 from repro.core.committee import committee_size, member
+from repro.core.monitor import Monitor
 from repro.data.replay import ReplayTrainingBuffer
 from repro.optim.adamw import QTensor, resolve_moments
 from repro.optim.memory_policy import MemoryPolicy, resolve_policy
@@ -104,7 +105,7 @@ class CommitteeTrainer:
         self.steps = int(steps)
         self.batch = int(batch)
         self.bootstrap = bool(bootstrap)
-        self.monitor = monitor
+        self.monitor = monitor if monitor is not None else Monitor()
         tcfg = train_cfg if train_cfg is not None else default_train_config(lr)
         policy = resolve_policy(memory_policy)
         if policy is None:
@@ -209,7 +210,8 @@ class CommitteeTrainer:
                     jnp.isfinite(leaf), axis=tuple(range(1, leaf.ndim)))
             return ok
 
-        def fused(cstate, xb, yb, size, key):
+        # traces as jit_committee_train_step
+        def committee_train_step(cstate, xb, yb, size, key):
             idx = self._draw_indices(key, size)             # (K, B)
             # (K, B, d) gather; cast back to fp32 ON DEVICE so a bf16
             # replay ring never leaks its storage dtype into the loss math
@@ -247,7 +249,7 @@ class CommitteeTrainer:
             # pytree prefix for whatever aux dict the loss emits
             kw["in_shardings"] = (cs, rep, rep, rep, rep)
             kw["out_shardings"] = (cs, rep)
-        return jax.jit(fused, **kw)
+        return jax.jit(committee_train_step, **kw)
 
     # ---------------------------------------------------------------- data
     def add_blocks(self, datapoints: Sequence[Tuple[np.ndarray, np.ndarray]]):
@@ -281,41 +283,45 @@ class CommitteeTrainer:
         moment new labels arrive, like the paper's ``retrain`` loop.
         Returns the last step's per-member metrics (host numpy)."""
         n_steps = self.steps if steps is None else int(steps)
-        with self._lock:
-            if len(self.replay) == 0 or n_steps <= 0:
-                return {}
-            metrics = None
-            done = 0
-            for _ in range(n_steps):
-                # per-step state lock: the ring handles are re-fetched
-                # inside it so a concurrent add_blocks (which donates and
-                # replaces the buffers) can never leave this step holding
-                # a deleted array, and a concurrent state_dict sees a
-                # consistent (cstate, _step_seq) pair
-                with self._state_lock:
-                    xb, yb, size = self.replay.arrays()
-                    key = jax.random.fold_in(self._key, self._step_seq)
-                    self._step_seq += 1
-                    self.cstate, metrics = self._fused(
-                        self.cstate, xb, yb, np.int32(size), key)
-                    self.steps_done += 1
-                done += 1
-                if interrupt is not None and interrupt.test():
-                    break
-            self.rounds += 1
-            self._last_metrics = metrics
-            if self.monitor is not None:
-                self.monitor.incr("train.fused_steps", done)
-        out = jax.tree.map(np.asarray, metrics)
-        # rollback accounting rides the round's existing host conversion —
-        # zero extra device syncs (the per-step mask never leaves the chip
-        # mid-round; only the final step's verdict is inspected here)
-        ok = out.get("member_ok") if isinstance(out, dict) else None
-        if ok is not None:
-            self.last_member_ok = np.asarray(ok, bool)
-            bad = int((~self.last_member_ok).sum())
-            if bad and self.monitor is not None:
-                self.monitor.incr("train.member_rollbacks", bad)
+        mon = self.monitor
+        with mon.span("trainer.round", step=self._step_seq):
+            with self._lock:
+                if len(self.replay) == 0 or n_steps <= 0:
+                    return {}
+                metrics = None
+                done = 0
+                for _ in range(n_steps):
+                    # per-step state lock: the ring handles are re-fetched
+                    # inside it so a concurrent add_blocks (which donates
+                    # and replaces the buffers) can never leave this step
+                    # holding a deleted array, and a concurrent state_dict
+                    # sees a consistent (cstate, _step_seq) pair
+                    with mon.span("trainer.dispatch", step=self._step_seq), \
+                            self._state_lock:
+                        xb, yb, size = self.replay.arrays()
+                        key = jax.random.fold_in(self._key, self._step_seq)
+                        self._step_seq += 1
+                        self.cstate, metrics = self._fused(
+                            self.cstate, xb, yb, np.int32(size), key)
+                        self.steps_done += 1
+                    done += 1
+                    if interrupt is not None and interrupt.test():
+                        break
+                self.rounds += 1
+                self._last_metrics = metrics
+                mon.incr("train.fused_steps", done)
+            with mon.span("trainer.sync"):
+                out = jax.tree.map(np.asarray, metrics)
+            # rollback accounting rides the round's existing host
+            # conversion — zero extra device syncs (the per-step mask never
+            # leaves the chip mid-round; only the final step's verdict is
+            # inspected here)
+            ok = out.get("member_ok") if isinstance(out, dict) else None
+            if ok is not None:
+                self.last_member_ok = np.asarray(ok, bool)
+                bad = int((~self.last_member_ok).sum())
+                if bad:
+                    mon.incr("train.member_rollbacks", bad)
         return out
 
     # ------------------------------------------------------------- weights
@@ -353,8 +359,7 @@ class CommitteeTrainer:
                     jnp.nan, leaf),
                 self.cstate.params)
             self.cstate = self.cstate._replace(params=params)
-        if self.monitor is not None:
-            self.monitor.incr("train.members_poisoned")
+        self.monitor.incr("train.members_poisoned")
 
     # ---------------------------------------------------------- checkpoint
     def state_dict(self) -> Dict[str, Any]:

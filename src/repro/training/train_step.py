@@ -86,11 +86,15 @@ def make_train_step(
         return loss, metrics, grads
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        loss, metrics, grads = compute_grads(state.params, batch)
-        grads, gnorm = clip_by_global_norm(grads, train_cfg.grad_clip_norm)
-        lr = schedule(state.step)
-        new_params, new_opt = adamw_update(grads, state.opt, state.params,
-                                           lr, adam_cfg)
+        # the named scopes label the step's operations in a device profile
+        with jax.named_scope("loss"):
+            loss, metrics, grads = compute_grads(state.params, batch)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads,
+                                               train_cfg.grad_clip_norm)
+            lr = schedule(state.step)
+            new_params, new_opt = adamw_update(grads, state.opt,
+                                               state.params, lr, adam_cfg)
         metrics = dict(metrics)
         metrics.update({"loss": loss, "grad_norm": gnorm, "lr": lr})
         return TrainState(state.step + 1, new_params, new_opt), metrics

@@ -15,6 +15,7 @@ library at a time, and it keeps it until it exits.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,5 +114,8 @@ def test_fused_pallas_score_compiles_on_v5e_meshes(topo, shape):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar_sh),
         (),
     )
-    compiled = eng._compiled_locked(nb).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = eng._compiled_locked(nb).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the program and the kernel carry the names a device trace shows
+    assert "HloModule jit_engine_score" in text
+    assert re.search(r"%committee_uq[.\d]* = [^\n]*tpu_custom_call", text)
