@@ -11,6 +11,7 @@ potentials) used as the DFT stand-in ground truth in examples and tests.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Tuple
 
 import jax
@@ -36,14 +37,45 @@ def _pair_distances(coords: jnp.ndarray) -> jnp.ndarray:
     return jnp.sqrt(d2 + 1e-12)
 
 
-def descriptors(coords: jnp.ndarray, cfg: PotentialConfig) -> jnp.ndarray:
-    """(A, 3) -> (A, n_rbf) summed Gaussian RBFs with cosine cutoff."""
-    d = _pair_distances(coords)                       # (A, A)
+def _radial_terms(d: jnp.ndarray, cfg: PotentialConfig):
+    """(A, A) distances -> the Gaussians (A, A, n_rbf), the cosine cutoff
+    (A, A), and what d/dd of their product needs: centres and gamma."""
     centers = jnp.linspace(0.5, cfg.r_cut, cfg.n_rbf)
     gamma = (cfg.n_rbf / cfg.r_cut) ** 2
     rbf = jnp.exp(-gamma * (d[..., None] - centers) ** 2)   # (A, A, n_rbf)
     fcut = 0.5 * (jnp.cos(jnp.pi * jnp.clip(d / cfg.r_cut, 0, 1)) + 1.0)
+    return rbf, fcut, centers, gamma
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+def descriptors(coords: jnp.ndarray, cfg: PotentialConfig) -> jnp.ndarray:
+    """(A, 3) -> (A, n_rbf) summed Gaussian RBFs with cosine cutoff.
+
+    Differentiated by the hand-written JVP below, in forward and reverse
+    mode."""
+    rbf, fcut, _, _ = _radial_terms(_pair_distances(coords), cfg)
     return jnp.sum(rbf * fcut[..., None], axis=1)     # (A, n_rbf)
+
+
+@descriptors.defjvp
+def _descriptors_jvp(cfg, primals, tangents):
+    """dG[i,r] = sum_j w[i,j,r] dd[i,j] with the single derivative
+    w = d(rbf * fcut)/dd.  Autodiff would follow the product rule into
+    two (A, A, n_rbf) terms, each reduced over the radial axis in
+    reverse mode; one contraction lets the committee's members, which
+    share the coordinates, read each w once in one dot."""
+    (coords,), (dcoords,) = primals, tangents
+    d, dd = jax.jvp(_pair_distances, (coords,), (dcoords,))
+    rbf, fcut, centers, gamma = _radial_terms(d, cfg)
+    g = jnp.sum(rbf * fcut[..., None], axis=1)
+    # the cutoff's slope: 0 beyond r_cut, where the clip is flat
+    dfcut = jnp.where(d < cfg.r_cut, -(jnp.pi / (2 * cfg.r_cut))
+                      * jnp.sin(jnp.pi * d / cfg.r_cut), 0.0)
+    w = rbf * (fcut[..., None] * (-2.0 * gamma) * (d[..., None] - centers)
+               + dfcut[..., None])
+    dg = jnp.einsum("ijr,ij->ir", w, dd,
+                    precision=jax.lax.Precision.HIGHEST)
+    return g, dg
 
 
 # ---------------------------------------------------------------------------

@@ -70,9 +70,7 @@ def test_committee_uq_compiles_for_v5e(one_chip, K, n, d):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _member_forces(p, flat_batch):
-    cfg = PotentialConfig()
-
+def _member_forces(p, flat_batch, cfg=PotentialConfig()):
     def one(flat):
         _, f = pot.energy_forces(p, flat.reshape(cfg.n_atoms, 3), cfg)
         return f.reshape(-1)
@@ -119,3 +117,27 @@ def test_fused_pallas_score_compiles_on_v5e_meshes(topo, shape):
     # the program and the kernel carry the names a device trace shows
     assert "HloModule jit_engine_score" in text
     assert re.search(r"%committee_uq[.\d]* = [^\n]*tpu_custom_call", text)
+
+
+def test_fleet_member_forces_hold_under_one_pair_tensor(one_chip):
+    """The fleet's member forces at the fleet cell's widths (the ANI-1x
+    ensemble's 8 members, 384-160-128-96-1, 64 atoms, r_cut 5.2) for 64
+    walkers, the committee sharing the walkers' coordinates: the
+    descriptor's chain rule needs at most 1.5 f32 (N, A, A, n_rbf) pair
+    tensors of temporaries.  Autodiff's product rule held two of them,
+    one per branch, each contracted with the members' cotangents."""
+    cfg = PotentialConfig(n_atoms=64, committee_size=8, hidden=(160, 128, 96),
+                          n_rbf=384, r_cut=5.2)
+    n = 64
+    cp_shape = jax.eval_shape(
+        lambda: pot.init_committee(cfg, jax.random.PRNGKey(0)))
+    cparams = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), cp_shape)
+    x = jax.ShapeDtypeStruct((n, 3 * cfg.n_atoms), jnp.float32,
+                             sharding=one_chip)
+    forces = jax.vmap(lambda p, xb: _member_forces(p, xb, cfg),
+                      in_axes=(0, None))
+    compiled = jax.jit(forces).lower(cparams, x).compile()
+    pair_tensor = 4 * n * cfg.n_atoms * cfg.n_atoms * cfg.n_rbf
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.5 * pair_tensor, temp / pair_tensor
