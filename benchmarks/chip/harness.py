@@ -1,8 +1,10 @@
 """Runs one cell of ``BENCHMARK.json`` once and builds its result line.
 
 Everything that belongs to one cell is found by name: the configuration
-file the cell's ``config`` entry names, ``traffic/<traffic>.json`` (its
-``loop`` names the module under ``loops/`` that drives it),
+file the cell's ``config`` entry names (its ``model`` names the
+architecture: ``archs/<model>.py``, the program side, and
+``archs/<model>_ref.py``, its plain reference), ``traffic/<traffic>.json``
+(its ``loop`` names the module under ``loops/`` that drives it),
 ``limits/<cell>.json`` (one limit per number compared) and one reader
 ``metrics/<metric>.py`` per metric the cell reports.  A reader maps the
 run's record to a number, or to ``None`` when it finds nothing to read,
@@ -12,6 +14,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -20,12 +23,13 @@ import shutil
 import sys
 import time
 import zlib
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
+ARCHS = os.path.join(HERE, "archs")
 SPAN_NAMES = ("window", "exchange.step", "trainer.train")
 
 
@@ -52,6 +56,34 @@ def load_module(path: str, name: str):
     sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+class Arch(NamedTuple):
+    """The two modules of one architecture."""
+    program: Any        # make_weights, member_functions, *_step_flops
+    reference: Any      # forces, importing nothing of the program
+
+
+def arch(cfg: Dict[str, Any]) -> Arch:
+    """The architecture a configuration names under ``model``: the files
+    ``<model>.py`` and ``<model>_ref.py`` of ``ARCHS``, each loaded once.
+    A configuration without a ``model``, or one that names none of them,
+    is an error that lists the known names."""
+    known = sorted(f[:-3] for f in os.listdir(ARCHS)
+                   if f.endswith(".py") and not f.endswith("_ref.py"))
+    name = cfg.get("model")
+    if name not in known:
+        raise KeyError(f"configuration {cfg.get('name')!r} names model "
+                       f"{name!r}; known: {known}")
+    return _load_arch(ARCHS, name)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_arch(folder: str, name: str) -> Arch:
+    return Arch(load_module(os.path.join(folder, name + ".py"),
+                            "arch_" + name),
+                load_module(os.path.join(folder, name + "_ref.py"),
+                            "arch_" + name + "_ref"))
 
 
 def derive_seed(seed: int, tag: str) -> int:

@@ -4,7 +4,7 @@ Copied into the benchmark from ``chip_smoke.py`` (``potential()``'s
 lattice sampler and LJ oracle, and ``close``; ``compare_uq``'s rule for
 mask flips near the threshold is in ``loops/exchange.py``) so that the
 yardstick does not move when that script does.  The LJ energy is the one
-``repro.models.potential.lennard_jones`` computes (eps = sigma = 1), written
+the program's own ``lennard_jones`` computes (eps = sigma = 1), written
 out here again.
 """
 from __future__ import annotations

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import harness
 import lattice
 import program
 import reference as ref
@@ -49,7 +50,8 @@ def setup(ctx) -> State:
     cfg, t = ctx.cfg, ctx.traffic
     n = n_walkers(ctx)
     with ctx.spans("setup.weights"):
-        cparams = program.make_weights(cfg, ctx.seed_for("weights"))
+        cparams = harness.arch(cfg).program.make_weights(
+            cfg, ctx.seed_for("weights"))
         x0 = lattice.geometries(
             np.random.RandomState(ctx.seed_for("walkers")), n,
             program.base_geometry(cfg), cfg["geometry"]["perturb"])

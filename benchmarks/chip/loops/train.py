@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import harness
 import lattice
 import program
 import reference as ref
@@ -58,7 +59,8 @@ def make_ring(cfg, rows: int, seed: int):
 def setup(ctx) -> State:
     cfg, t = ctx.cfg, ctx.traffic
     with ctx.spans("setup.weights"):
-        cparams = program.make_weights(cfg, ctx.seed_for("weights"))
+        cparams = harness.arch(cfg).program.make_weights(
+            cfg, ctx.seed_for("weights"))
         x, y = make_ring(cfg, t["replay_rows"], ctx.seed_for("ring"))
         x_ring, y_ring = np.asarray(x), np.asarray(y)
         p0 = jax.tree.map(np.asarray, cparams)

@@ -2,10 +2,11 @@
 
 Everything here calls into ``repro``: ``PAL(...)`` from a
 ``PALRunConfig`` builds the acquisition engine, the walker fleet, the
-exchange and the committee trainer; the member forward is
-``repro.models.potential.energy_forces``.  The benchmark only supplies
-what a user supplies: the weights (made on the device from the seed),
-the walkers' starting geometries, the oracle and the per-member loss.
+exchange and the committee trainer; the member functions come from the
+architecture the configuration names (``harness.arch``).  The benchmark
+only supplies what a user supplies: the weights (made on the device from
+the seed), the walkers' starting geometries, the oracle and the
+per-member loss.
 """
 from __future__ import annotations
 
@@ -13,65 +14,15 @@ import os
 import tempfile
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+import harness
 import lattice
-
-
-def potential_config(cfg):
-    from repro.configs.pal_potential import PotentialConfig
-
-    return PotentialConfig(
-        name=cfg["name"], n_atoms=cfg["n_atoms"],
-        committee_size=cfg["committee_size"], hidden=tuple(cfg["hidden"]),
-        n_rbf=cfg["n_rbf"], r_cut=cfg["r_cut"], dtype=cfg["dtype"])
-
-
-def make_weights(cfg, seed: int):
-    """Stacked (K, ...) member weights, float32, made on the device in one
-    jitted call: w_i ~ N(0, 1) * w_scale / sqrt(fan_in), b_i ~ N(0, 1) *
-    b_scale."""
-    dims = [cfg["n_rbf"], *cfg["hidden"], 1]
-    k = cfg["committee_size"]
-    ws, bs = cfg["weights"]["w_scale"], cfg["weights"]["b_scale"]
-
-    @jax.jit
-    def init(key):
-        out = {}
-        keys = jax.random.split(key, 2 * (len(dims) - 1))
-        for i, (a, b) in enumerate(zip(dims[:-1], dims[1:])):
-            out[f"w{i}"] = jax.random.normal(keys[2 * i], (k, a, b)) \
-                * (ws / np.sqrt(a))
-            out[f"b{i}"] = jax.random.normal(keys[2 * i + 1], (k, b)) * bs
-        return out
-
-    return init(jax.random.PRNGKey(seed))
 
 
 def base_geometry(cfg):
     g = cfg["geometry"]
     return lattice.lattice(g["lattice"], g["spacing"])
-
-
-def member_functions(cfg):
-    """(member_forces, member_force_loss) over the program's model code."""
-    from repro.models import potential as pot
-
-    pcfg = potential_config(cfg)
-    n_atoms = pcfg.n_atoms
-
-    def member_forces(p, flat_batch):              # (n, 3A) -> (n, 3A)
-        def one(flat):
-            _, f = pot.energy_forces(p, flat.reshape(n_atoms, 3), pcfg)
-            return f.reshape(-1)
-        return jax.vmap(one)(flat_batch)
-
-    def member_force_loss(p, batch):
-        pred = member_forces(p, batch["x"])
-        return jnp.mean((pred - batch["y"]) ** 2), {}
-
-    return member_forces, member_force_loss
 
 
 def build_pal(cfg, traffic, seed: int, cparams, *, impl: str = "pallas",
@@ -123,7 +74,7 @@ def build_pal(cfg, traffic, seed: int, cparams, *, impl: str = "pallas",
         train_bootstrap=t.get("bootstrap", True),
         train_replay_capacity=t.get("replay_rows", 2048),
         train_memory_policy=t.get("memory_policy", "fp32"))
-    forces, loss = member_functions(cfg)
+    forces, loss = harness.arch(cfg).program.member_functions(cfg)
     return PAL(run_cfg, make_generator=LatticeGenerator,
                make_oracle=LJOracle,
                committee=CommitteeSpec(forces, cparams),

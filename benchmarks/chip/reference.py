@@ -1,17 +1,13 @@
 """The plain reference the benchmark holds the program to.
 
-Written from the equations of the committee MLP potential, in
-straightforward ``jax.numpy``.  It imports nothing of the program under
-test (no ``repro``) and is given nothing the program made except the
-state a checked step starts from: the weights and the data are made by
-the benchmark from the seed.
+Written from the equations, in straightforward ``jax.numpy``.  It
+imports nothing of the program under test (no ``repro``) and is given
+nothing the program made except the state a checked step starts from:
+the weights and the data are made by the benchmark from the seed.
 
-* descriptor: per atom i, G_ir = sum_{j != i} exp(-gamma (d_ij - c_r)^2)
-  f_c(d_ij), with c_r = linspace(0.5, r_cut, n_rbf), gamma =
-  (n_rbf / r_cut)^2 and the cosine cutoff f_c(d) = (cos(pi min(d /
-  r_cut, 1)) + 1) / 2;
-* member energy E = sum_i MLP(G_i), tanh between layers, and forces F =
-  -dE/dR;
+* member forces: ``forces`` of the reference side of the architecture
+  the configuration names (``archs/<model>_ref.py``, through
+  ``harness.arch``), computed here in blocks of rows;
 * committee statistics over K members: mean, ddof=1 std, its max over
   components (``scalar_std``) and its mean (``component_std``);
 * the Euler walker advance, the patience/restart update and the
@@ -27,10 +23,13 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import harness
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
@@ -43,45 +42,14 @@ def precision(dtype):
     return contextlib.nullcontext()
 
 
-# ------------------------------------------------------------------ model
-def descriptor(coords, n_rbf: int, r_cut: float):
-    """(A, 3) -> (A, n_rbf) radial descriptor."""
-    dt = coords.dtype
-    a = coords.shape[0]
-    eye = jnp.eye(a, dtype=dt)
-    diff = coords[:, None, :] - coords[None, :, :]
-    d = jnp.sqrt(jnp.sum(diff * diff, axis=-1) + eye)   # self pairs: 1
-    centers = jnp.linspace(0.5, r_cut, n_rbf).astype(dt)
-    gamma = jnp.asarray((n_rbf / r_cut) ** 2, dt)
-    g = jnp.exp(-gamma * (d[..., None] - centers) ** 2)  # (A, A, n_rbf)
-    fc = 0.5 * (jnp.cos(jnp.pi * jnp.minimum(d / r_cut, 1.0)) + 1.0)
-    return jnp.sum(g * (fc * (1.0 - eye))[..., None], axis=1)
-
-
-def energy(params, coords, cfg):
-    """Energy of one (A, 3) structure for one member's params."""
-    h = descriptor(coords, cfg["n_rbf"], cfg["r_cut"])
-    n = len(cfg["hidden"]) + 1
-    for i in range(n):
-        h = h @ params[f"w{i}"] + params[f"b{i}"]
-        if i < n - 1:
-            h = jnp.tanh(h)
-    return jnp.sum(h)
-
-
-def forces(params, flat, cfg):
-    """(3A,) -> (3A,) forces of one member."""
-    coords = flat.reshape(cfg["n_atoms"], 3)
-    return -jax.grad(energy, argnums=1)(params, coords, cfg).reshape(-1)
-
-
 def cast(tree, dtype):
     return jax.tree.map(lambda a: jnp.asarray(a, dtype), tree)
 
 
 @functools.lru_cache(maxsize=None)
-def _committee_fn(cfg_items, dtype):
-    cfg = dict(cfg_items)
+def _committee_fn(cfg_key, dtype):
+    cfg = json.loads(cfg_key)
+    forces = harness.arch(cfg).reference.forces
 
     def fn(cparams, x):
         return jax.vmap(lambda p: jax.vmap(
@@ -90,9 +58,8 @@ def _committee_fn(cfg_items, dtype):
 
 
 def _key(cfg):
-    return tuple((k, tuple(v) if isinstance(v, list) else v)
-                 for k, v in sorted(cfg.items())
-                 if k in ("n_atoms", "n_rbf", "r_cut", "hidden"))
+    """The whole configuration, as the key of its jitted functions."""
+    return json.dumps(cfg, sort_keys=True)
 
 
 def committee_forces(cparams, x, cfg, dtype=F32, block: int = 128):
@@ -185,6 +152,7 @@ def budget_update(state, n_selected: int, n_valid: int, target: float,
 # ---------------------------------------------------------------- training
 def force_loss(params, x, y, cfg):
     """mean((F(x) - y)^2) over the rows and components of a minibatch."""
+    forces = harness.arch(cfg).reference.forces
     pred = jax.vmap(lambda r: forces(params, r, cfg))(x)
     return jnp.mean((pred - y) ** 2)
 
@@ -199,8 +167,8 @@ def draw_indices(key, n_members: int, batch: int, size: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _member_grad_fn(cfg_items, dtype):
-    cfg = dict(cfg_items)
+def _member_grad_fn(cfg_key, dtype):
+    cfg = json.loads(cfg_key)
     return jax.jit(jax.value_and_grad(
         lambda p, x, y: force_loss(p, x, y, cfg)))
 

@@ -1,6 +1,9 @@
 """CPU tests of the on-chip benchmark's yardstick: the analytic FLOP and
 byte counts, the trace reduction, the reference against the program's
-model code, and the loading of cells, configurations and peaks."""
+model code, and the loading of cells, configurations, architectures and
+peaks."""
+import ast
+import glob
 import json
 import os
 import sys
@@ -20,8 +23,8 @@ import program  # noqa: E402
 import reference as ref  # noqa: E402
 import xplane  # noqa: E402
 
-TINY = dict(name="tiny", n_atoms=4, committee_size=3, hidden=[5, 6],
-            n_rbf=7, r_cut=3.0, dtype="float32",
+TINY = dict(name="tiny", model="mlp_potential", n_atoms=4, committee_size=3,
+            hidden=[5, 6], n_rbf=7, r_cut=3.0, dtype="float32",
             geometry=dict(lattice=[2, 2, 1], spacing=1.3, perturb=0.05),
             weights=dict(w_scale=1.0, b_scale=0.1))
 
@@ -95,7 +98,7 @@ def test_reduce_recorded_trace():
 # ------------------------------------------------------------- reference
 @pytest.fixture(scope="module")
 def tiny():
-    cp = program.make_weights(TINY, 3)
+    cp = harness.arch(TINY).program.make_weights(TINY, 3)
     x = lattice.geometries(np.random.RandomState(0), 5,
                            program.base_geometry(TINY), 0.05)
     return cp, x
@@ -103,7 +106,7 @@ def tiny():
 
 def test_reference_forces_match_program(tiny):
     cp, x = tiny
-    forces, _ = program.member_functions(TINY)
+    forces, _ = harness.arch(TINY).program.member_functions(TINY)
     with jax.default_matmul_precision("highest"):
         got = np.asarray(jax.vmap(forces, in_axes=(0, None))(cp, x))
     want = ref.committee_forces(cp, x, TINY)
@@ -112,7 +115,7 @@ def test_reference_forces_match_program(tiny):
 
 def test_reference_loss_gradient_matches_program(tiny):
     cp, x = tiny
-    _, loss = program.member_functions(TINY)
+    _, loss = harness.arch(TINY).program.member_functions(TINY)
     y = np.asarray(jax.vmap(lambda r: lattice.lj_forces(r, 4))(x))
     p = jax.tree.map(lambda a: a[0], cp)
     with jax.default_matmul_precision("highest"):
@@ -194,6 +197,7 @@ def test_every_cell_has_its_files():
     for cell in bench["workloads"]:
         spec = harness.cell_spec(cell["name"])
         assert spec["cfg"]["name"] == cell["config"]
+        harness.arch(spec["cfg"])
         assert os.path.exists(os.path.join(
             HERE, "loops", spec["traffic"]["loop"] + ".py"))
         assert spec["end_to_end"] and spec["per_layer"]
@@ -204,6 +208,31 @@ def test_every_cell_has_its_files():
         cfg = harness.load_json(os.path.join(harness.ROOT, conf["file"]))
         assert cfg["name"] == conf["name"] and cfg["source"] == conf["source"]
         assert cfg["reduced"] == conf["reduced"]
+
+
+@pytest.mark.parametrize("model", [None, "no_such_model"])
+def test_missing_or_unknown_model_raises(model):
+    cfg = {k: v for k, v in TINY.items() if k != "model"}
+    if model is not None:
+        cfg["model"] = model
+    with pytest.raises(KeyError, match=r"known: \[.*'mlp_potential'"):
+        harness.arch(cfg)
+
+
+def test_reference_sides_import_nothing_of_the_program():
+    refs = sorted(glob.glob(os.path.join(HERE, "archs", "*_ref.py"))
+                  + glob.glob(os.path.join(HERE, "tests", "data",
+                                           "*_ref.py")))
+    assert os.path.join(HERE, "archs", "mlp_potential_ref.py") in refs
+    for path in refs:
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        mods = [a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+                for a in n.names]
+        mods += [n.module or "" for n in ast.walk(tree)
+                 if isinstance(n, ast.ImportFrom)]
+        assert mods and not [m for m in mods if m.split(".")[0] in (
+            "repro", "program", "harness")], (path, mods)
 
 
 def test_unknown_workload_and_device_raise():
@@ -253,7 +282,8 @@ def test_fleet_threshold_starts_above_the_committee_std(seed):
     selects nothing for dozens of steps, past the warm-up."""
     spec = harness.cell_spec("fleet.mlp-pot-ani1x-widths")
     cfg = spec["cfg"]
-    cparams = program.make_weights(cfg, harness.derive_seed(seed, "weights"))
+    cparams = harness.arch(cfg).program.make_weights(
+        cfg, harness.derive_seed(seed, "weights"))
     x = lattice.geometries(
         np.random.RandomState(harness.derive_seed(seed, "walkers")), 8,
         program.base_geometry(cfg), cfg["geometry"]["perturb"])
