@@ -141,3 +141,56 @@ def test_fleet_member_forces_hold_under_one_pair_tensor(one_chip):
     pair_tensor = 4 * n * cfg.n_atoms * cfg.n_atoms * cfg.n_rbf
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 1.5 * pair_tensor, temp / pair_tensor
+
+
+def test_painn_fleet_step_fits_with_vectors_off_the_minor_tiles(one_chip):
+    """The whole fused fleet step (``jit_engine_step_score``) of the
+    benchmark's PaiNN fleet cell: 8 members at the published widths (F=128,
+    3 blocks, 20 radial functions), 64 atoms, the cell's walker count.
+    Its temporaries leave at least a tenth of the v5e's 16 GB free, the
+    rule the cell's walker count was chosen by.  No buffer that carries
+    the feature axis or a pair of atom axes holds the spatial axis among
+    its two minor (tiled) dimensions, where 3 would pad to 8 sublanes or
+    128 lanes."""
+    import json
+
+    from repro.exploration.fleet import FleetConfig, WalkerFleet
+    from repro.models import painn
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "traffic",
+                           "fleet-mem.json")) as f:
+        n = json.load(f)["walkers"]
+    cfg = painn.PaiNNConfig()
+    k, a, f = 8, 64, cfg.features
+    species = jnp.asarray(np.arange(a) % 4 * 2 + 1)     # H, N, ...
+
+    def member_forces(p, flat_batch):
+        return jax.vmap(lambda x: painn.energy_forces(
+            p, x.reshape(a, 3), species, cfg)[1].reshape(-1))(flat_batch)
+
+    shapes = painn.param_shapes(cfg)
+    cparams = jax.tree.map(lambda s: jnp.zeros((k,) + s, jnp.float32),
+                           shapes, is_leaf=lambda x: isinstance(x, tuple))
+    eng = acq.FusedEngine(member_forces, cparams, 1.0, impl="pallas")
+    fleet = WalkerFleet(eng, np.zeros((n, 3 * a), np.float32),
+                        FleetConfig())
+    step = eng._step_compiled_locked("fleet", fleet.nb, fleet._step_fn,
+                                     fleet._react_fn)
+    args = jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(np.shape(v), jnp.asarray(v).dtype,
+                                       sharding=one_chip),
+        (cparams, fleet._carry, np.int32(n), np.int32(0), eng.rule_state))
+    compiled = step.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes <= 0.9 * 16e9
+    text = compiled.as_text()
+    assert "HloModule jit_engine_step_score" in text
+    misplaced = []
+    for dims, layout in re.findall(r"\[([\d,]+)\]\{([\d,]+)", text):
+        dims = [int(d) for d in dims.split(",")]
+        minor = [dims[int(i)] for i in layout.split(",")[:2]]
+        pairs = any(x == y == a for x, y in zip(dims, dims[1:]))
+        if 3 in minor and len(dims) > 2 and (f in dims or pairs):
+            misplaced.append((dims, layout))
+    assert not misplaced, misplaced[:5]
