@@ -194,3 +194,50 @@ def test_painn_fleet_step_fits_with_vectors_off_the_minor_tiles(one_chip):
         if 3 in minor and len(dims) > 2 and (f in dims or pairs):
             misplaced.append((dims, layout))
     assert not misplaced, misplaced[:5]
+
+
+def test_painn_fleet_step_writes_no_pair_tensor_of_feature_width(one_chip):
+    """The PaiNN fleet cell's fused step, as above: the messages are
+    contracted with the (A, A, R + 1) pair basis that the members share,
+    so no buffer holds two adjacent atom axes followed by an axis of F or
+    wider (the per-member filter (K, N, A, A, 9F) and its messages were
+    8.5 GB of temporaries at 16 walkers), and the temporaries stay under
+    3 GB."""
+    import json
+
+    from repro.exploration.fleet import FleetConfig, WalkerFleet
+    from repro.models import painn
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "traffic",
+                           "fleet-mem.json")) as f:
+        n = json.load(f)["walkers"]
+    cfg = painn.PaiNNConfig()
+    k, a = 8, 64
+    species = jnp.asarray(np.arange(a) % 4 * 2 + 1)
+
+    def member_forces(p, flat_batch):
+        return jax.vmap(lambda x: painn.energy_forces(
+            p, x.reshape(a, 3), species, cfg)[1].reshape(-1))(flat_batch)
+
+    cparams = jax.tree.map(lambda s: jnp.zeros((k,) + s, jnp.float32),
+                           painn.param_shapes(cfg),
+                           is_leaf=lambda x: isinstance(x, tuple))
+    eng = acq.FusedEngine(member_forces, cparams, 1.0, impl="pallas")
+    fleet = WalkerFleet(eng, np.zeros((n, 3 * a), np.float32),
+                        FleetConfig())
+    step = eng._step_compiled_locked("fleet", fleet.nb, fleet._step_fn,
+                                     fleet._react_fn)
+    args = jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(np.shape(v), jnp.asarray(v).dtype,
+                                       sharding=one_chip),
+        (cparams, fleet._carry, np.int32(n), np.int32(0), eng.rule_state))
+    compiled = step.lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3e9
+    wide = set()
+    for dims in re.findall(r"\[([\d,]+)\]", compiled.as_text()):
+        dims = [int(d) for d in dims.split(",")]
+        if any(dims[i] == dims[i + 1] == a and dims[i + 2] >= cfg.features
+               for i in range(len(dims) - 2)):
+            wide.add(tuple(dims))
+    assert not wide, sorted(wide)[:5]
